@@ -1,15 +1,21 @@
 """Flash attention (causal/windowed, GQA) as a Pallas TPU kernel.
 
-TPU-native design (see DESIGN.md §6):
+TPU-native design:
+  * head-major layout: q [B, H, Sq, D], k/v [B, K, Skv, D]. Batch and head block
+    dims are squeezed (``None``), so every block's last two dims are
+    (seq block, D) — tiling-aligned (blocks of 128, D a multiple of 128) or the
+    whole array dim, as the TPU compiler requires.
   * grid = (batch, q_heads, num_q_blocks, num_kv_blocks); the innermost grid dim is
     sequential on TPU, so VMEM scratch (acc/m/l) carries the online-softmax state
     across kv blocks — HBM→VMEM streams one (blk_q × d) q tile and one (blk_kv × d)
     k/v tile at a time.
-  * blocks are MXU-aligned (128); head_dim is padded to a multiple of 128 by ops.py.
+  * m/l scratch and the lse output are (blk_q, 128) tiles with the value
+    replicated across lanes: a 1-D or single-lane VMEM buffer does not tile.
   * GQA is expressed in the k/v BlockSpec index_map (q head h reads kv head h//group),
     so no repeat_kv materialization ever happens.
-  * causal + sliding-window masks are computed from global block offsets; fully-masked
-    blocks still occupy grid slots but short-circuit through pl.when.
+  * causal + sliding-window masks are computed from global block offsets (q token
+    i sits at absolute position i + Skv - Sq); fully-masked blocks still occupy
+    grid slots but short-circuit through pl.when.
 """
 from __future__ import annotations
 
@@ -22,15 +28,16 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
+LANES = 128
 
 
-def _attn_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
+def _attn_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref, *,
                  scale: float, causal: bool, window: int, blk_q: int, blk_kv: int,
-                 num_kv_blocks: int, seq_q: int, seq_kv: int):
+                 num_kv_blocks: int, offset: int, seq_kv: int):
     qi = pl.program_id(2)
     kj = pl.program_id(3)
 
-    q_start = qi * blk_q
+    q_start = qi * blk_q + offset          # absolute position of the block's row 0
     k_start = kj * blk_kv
 
     @pl.when(kj == 0)
@@ -49,9 +56,9 @@ def _attn_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
 
     @pl.when(reachable)
     def _compute():
-        q = q_ref[0, :, 0, :].astype(jnp.float32) * scale          # [blk_q, d]
-        k = k_ref[0, :, 0, :].astype(jnp.float32)                  # [blk_kv, d]
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
+        q = q_ref[...].astype(jnp.float32) * scale                 # [blk_q, d]
+        k = k_ref[...].astype(jnp.float32)                         # [blk_kv, d]
+        v = v_ref[...].astype(jnp.float32)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())))    # [blk_q, blk_kv]
 
         q_pos = q_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
@@ -62,29 +69,36 @@ def _attn_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
         if window > 0:
             mask = jnp.logical_and(mask, q_pos - k_pos < window)
         s = jnp.where(mask, s, NEG_INF)
+        if seq_kv % blk_kv:
+            # rows past the end of a ragged last block hold whatever was in the
+            # buffer: zero them so 0 * garbage cannot turn into NaN
+            row = k_start + jax.lax.broadcasted_iota(jnp.int32, v.shape, 0)
+            v = jnp.where(row < seq_kv, v, 0.0)
 
-        m_prev = m_ref[...]
-        m_cur = jnp.maximum(m_prev, jnp.max(s, axis=1))
+        m_prev = m_ref[...]                                        # [blk_q, 128]
+        m_cur = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
         alpha = jnp.exp(m_prev - m_cur)
-        p = jnp.exp(s - m_cur[:, None])
+        p = jnp.exp(s - m_cur[:, :1])
         p = jnp.where(mask, p, 0.0)
-        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=1)
-        acc_ref[...] = acc_ref[...] * alpha[:, None] + p @ v
+        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * alpha[:, :1] + jax.lax.dot_general(
+            p, v, (((1,), (0,)), ((), ())))
         m_ref[...] = m_cur
 
     @pl.when(kj == num_kv_blocks - 1)
     def _finalize():
-        denom = jnp.maximum(l_ref[...], 1e-30)[:, None]
-        o_ref[0, :, 0, :] = (acc_ref[...] / denom).astype(o_ref.dtype)
+        l = jnp.maximum(l_ref[...], 1e-30)
+        o_ref[...] = (acc_ref[...] / l[:, :1]).astype(o_ref.dtype)
+        lse_ref[...] = m_ref[...] + jnp.log(l)
 
 
 def flash_attention_pallas(q, k, v, *, causal: bool = True, window: int = 0,
                            blk_q: int = 128, blk_kv: int = 128,
                            interpret: bool = False):
-    """q: [B, Sq, H, D]; k, v: [B, Skv, K, D] with H % K == 0. D must be 128-aligned
-    (ops.py pads). Returns [B, Sq, H, D]."""
-    B, Sq, H, D = q.shape
-    _, Skv, K, _ = k.shape
+    """q: [B, H, Sq, D]; k, v: [B, K, Skv, D] with H % K == 0. D must be 128-aligned
+    (ops.py pads). Returns (o [B, H, Sq, D], lse [B, H, Sq] float32)."""
+    B, H, Sq, D = q.shape
+    _, K, Skv, _ = k.shape
     assert H % K == 0, (H, K)
     group = H // K
     blk_q = min(blk_q, Sq)
@@ -95,22 +109,26 @@ def flash_attention_pallas(q, k, v, *, causal: bool = True, window: int = 0,
 
     kernel = functools.partial(
         _attn_kernel, scale=scale, causal=causal, window=window,
-        blk_q=blk_q, blk_kv=blk_kv, num_kv_blocks=nkv, seq_q=Sq, seq_kv=Skv)
+        blk_q=blk_q, blk_kv=blk_kv, num_kv_blocks=nkv, offset=Skv - Sq,
+        seq_kv=Skv)
 
-    return pl.pallas_call(
+    q_spec = pl.BlockSpec((None, None, blk_q, D), lambda b, h, i, j: (b, h, i, 0))
+    kv_spec = pl.BlockSpec((None, None, blk_kv, D),
+                           lambda b, h, i, j, g=group: (b, h // g, j, 0))
+    lse_spec = pl.BlockSpec((None, None, blk_q, LANES),
+                            lambda b, h, i, j: (b, h, i, 0))
+    o, lse = pl.pallas_call(
         kernel,
         grid=(B, H, nq, nkv),
-        in_specs=[
-            pl.BlockSpec((1, blk_q, 1, D), lambda b, h, i, j: (b, i, h, 0)),
-            pl.BlockSpec((1, blk_kv, 1, D), lambda b, h, i, j, g=group: (b, j, h // g, 0)),
-            pl.BlockSpec((1, blk_kv, 1, D), lambda b, h, i, j, g=group: (b, j, h // g, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, blk_q, 1, D), lambda b, h, i, j: (b, i, h, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, Sq, H, D), q.dtype),
+        in_specs=[q_spec, kv_spec, kv_spec],
+        out_specs=[q_spec, lse_spec],
+        out_shape=[jax.ShapeDtypeStruct((B, H, Sq, D), q.dtype),
+                   jax.ShapeDtypeStruct((B, H, Sq, LANES), jnp.float32)],
         scratch_shapes=[
             pltpu.VMEM((blk_q, D), jnp.float32),
-            pltpu.VMEM((blk_q,), jnp.float32),
-            pltpu.VMEM((blk_q,), jnp.float32),
+            pltpu.VMEM((blk_q, LANES), jnp.float32),
+            pltpu.VMEM((blk_q, LANES), jnp.float32),
         ],
         interpret=interpret,
     )(q, k, v)
+    return o, lse[..., 0]

@@ -1,14 +1,18 @@
 """Public kernel ops with platform dispatch.
 
-impl resolution order:
-  * "pallas"  — pl.pallas_call TPU kernel (interpret=True on CPU for tests)
+impl resolution (``impl=None`` picks by backend, see ``resolve_impl``):
+  * "pallas"  — pl.pallas_call TPU kernel: the default on a TPU, and only there
+                (tests run it on the CPU with interpret=True)
   * "blocked" — pure-jnp block-streaming implementation with identical math and
-                O(S)-memory (the lowering target on CPU, incl. the multi-pod dry-run)
+                O(S)-memory: the default on every other backend (CPU, incl. the
+                multi-pod dry-run)
   * "naive"   — ref.py oracle (small shapes / tests only)
 
-``flash_attention`` carries a custom VJP implementing the block-wise flash backward
-(residuals are q, k, v, o, lse — O(S), never O(S^2)), so training at 4k–32k sequence
-lengths keeps linear attention memory on both forward and backward passes.
+Both ``flash_attention`` paths carry a custom VJP implementing the block-wise flash
+backward (residuals are q, k, v, o, lse — O(S), never O(S^2)), so training at
+4k–32k sequence lengths keeps linear attention memory on both forward and backward
+passes; the Pallas forward emits its lse for it. ``rmsnorm``'s Pallas forward has a
+jnp backward, since a pallas_call has no reverse-mode rule of its own.
 """
 from __future__ import annotations
 
@@ -27,8 +31,9 @@ from repro.kernels.ssd_scan import ssd_scan_pallas
 NEG_INF = -1e30
 
 
-def _default_impl() -> str:
-    return "pallas" if jax.default_backend() == "tpu" else "blocked"
+def resolve_impl(impl: Optional[str] = None) -> str:
+    """The implementation a kernel op runs for ``impl`` (None: by backend)."""
+    return impl or ("pallas" if jax.default_backend() == "tpu" else "blocked")
 
 
 # --------------------------------------------------------------------------- attention
@@ -145,6 +150,28 @@ def _flash_blocked_fwd(q, k, v, causal, window, blk_kv):
 _flash_blocked.defvjp(_flash_blocked_fwd, _flash_bwd_blocked)
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def _flash_pallas(q, k, v, causal, window, interpret):
+    return _flash_pallas_fwd(q, k, v, causal, window, interpret)[0]
+
+
+def _flash_pallas_fwd(q, k, v, causal, window, interpret):
+    """[B,S,H,D] in and out; the kernel runs head-major and also gives the
+    lse the blocked backward needs."""
+    hm = lambda t: t.transpose(0, 2, 1, 3)
+    o, lse = flash_attention_pallas(hm(q), hm(k), hm(v), causal=causal,
+                                    window=window, interpret=interpret)
+    o = hm(o)
+    return o, (q, k, v, o, lse)
+
+
+def _flash_pallas_bwd(causal, window, interpret, res, do):
+    return _flash_bwd_blocked(causal, window, 512, res, do)
+
+
+_flash_pallas.defvjp(_flash_pallas_fwd, _flash_pallas_bwd)
+
+
 def _pad_head_dim(x, mult=128):
     D = x.shape[-1]
     pad = (-D) % mult
@@ -157,7 +184,7 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
                     impl: Optional[str] = None, blk_kv: int = 512,
                     interpret: bool = False):
     """q [B,Sq,H,D], k/v [B,Skv,K,D] -> [B,Sq,H,D]. GQA via H % K == 0."""
-    impl = impl or _default_impl()
+    impl = resolve_impl(impl)
     if impl == "naive":
         return ref.attention_ref(q, k, v, causal=causal, window=window)
     if impl == "pallas":
@@ -167,8 +194,7 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
         if qp.shape[-1] != D0:
             # keep the softmax scale of the true head dim
             qp = qp * math.sqrt(qp.shape[-1] / D0)
-        out = flash_attention_pallas(qp, kp, vp, causal=causal, window=window,
-                                     interpret=interpret)
+        out = _flash_pallas(qp, kp, vp, causal, window, interpret)
         return out[..., :D0]
     return _flash_blocked(q, k, v, causal, window, blk_kv)
 
@@ -288,22 +314,55 @@ def _ssd_blocked(x, dt, a, bm, cm, chunk, init_state=None):
     return y, hT
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
+def _ssd_pallas(x, dt, a, bm, cm, chunk, interpret):
+    """Head-major kernel call on [B,S,...] inputs; backward recomputes through
+    the blocked path (same math)."""
+    y = ssd_scan_pallas(x.transpose(0, 2, 1, 3),
+                        dt.transpose(0, 2, 1)[:, :, None],
+                        a.astype(jnp.float32), bm, cm, chunk=chunk,
+                        interpret=interpret)
+    return y.transpose(0, 2, 1, 3)
+
+
+def _ssd_pallas_fwd(x, dt, a, bm, cm, chunk, interpret):
+    return _ssd_pallas(x, dt, a, bm, cm, chunk, interpret), (x, dt, a, bm, cm)
+
+
+def _ssd_pallas_bwd(chunk, interpret, res, dy):
+    _, vjp = jax.vjp(lambda *t: _ssd_blocked(*t, chunk)[0], *res)
+    return vjp(dy)
+
+
+_ssd_pallas.defvjp(_ssd_pallas_fwd, _ssd_pallas_bwd)
+
+
+def resolve_ssd_impl(impl: Optional[str], init_state, return_state: bool) -> str:
+    """``resolve_impl`` for the SSD scan. The Pallas kernel starts from a zero
+    state and returns no final state, so a call that passes one or asks for
+    one runs "blocked" instead — on a TPU too. Every call the SSM and hybrid
+    models make asks for the final state (ROADMAP 1.5)."""
+    impl = resolve_impl(impl)
+    if impl == "pallas" and (init_state is not None or return_state):
+        return "blocked"
+    return impl
+
+
 def ssd_scan(x, dt, a, bm, cm, *, chunk: int = 256, impl: Optional[str] = None,
              init_state=None, return_state: bool = False, interpret: bool = False):
-    impl = impl or _default_impl()
+    impl = resolve_ssd_impl(impl, init_state, return_state)
     if impl == "naive":
         y, h = ref.ssd_ref(x, dt, a, bm, cm)
     elif impl == "pallas":
+        # zero-padding the tail leaves every real position's output unchanged
         S = x.shape[1]
         Q = min(chunk, S)
         pad = (-S) % Q
-        if pad or init_state is not None or return_state:
-            # pallas path currently covers the steady-state (no initial state) case;
-            # fall back for the others
-            y, h = _ssd_blocked(x, dt, a, bm, cm, chunk, init_state)
-        else:
-            y = ssd_scan_pallas(x, dt, a, bm, cm, chunk=Q, interpret=interpret)
-            h = None
+        if pad:
+            tail = lambda t: jnp.pad(t, [(0, 0), (0, pad)] + [(0, 0)] * (t.ndim - 2))
+            x, dt, bm, cm = tail(x), tail(dt), tail(bm), tail(cm)
+        y = _ssd_pallas(x, dt, a, bm, cm, Q, interpret)[:, :S]
+        h = None
     else:
         y, h = _ssd_blocked(x, dt, a, bm, cm, chunk, init_state)
     return (y, h) if return_state else y
@@ -324,9 +383,34 @@ def ssd_decode_step(x, dt, a, bm, cm, state):
 
 
 # --------------------------------------------------------------------------- rmsnorm
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
+def _rmsnorm_pallas(x, scale, eps, interpret):
+    return rmsnorm_pallas(x, scale, eps=eps, interpret=interpret)
+
+
+def _rmsnorm_pallas_fwd(x, scale, eps, interpret):
+    return _rmsnorm_pallas(x, scale, eps, interpret), (x, scale)
+
+
+def _rmsnorm_pallas_bwd(eps, interpret, res, dy):
+    x, scale = res
+    xf = x.astype(jnp.float32)
+    r = jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
+    xhat = xf * r
+    g = dy.astype(jnp.float32) * scale.astype(jnp.float32)
+    dx = r * (g - xhat * jnp.mean(g * xhat, axis=-1, keepdims=True))
+    dscale = jnp.sum(dy.astype(jnp.float32) * xhat,
+                     axis=tuple(range(x.ndim - 1)))
+    return dx.astype(x.dtype), dscale.astype(scale.dtype)
+
+
+_rmsnorm_pallas.defvjp(_rmsnorm_pallas_fwd, _rmsnorm_pallas_bwd)
+
+
 def rmsnorm(x, scale, *, eps: float = 1e-6, impl: Optional[str] = None,
             interpret: bool = False):
-    impl = impl or ("pallas" if jax.default_backend() == "tpu" else "naive")
-    if impl == "pallas":
-        return rmsnorm_pallas(x, scale, eps=eps, interpret=interpret)
+    """Pallas on a TPU; anywhere else the jnp form (ref.py), which XLA
+    fuses as well as any blocked variant would."""
+    if resolve_impl(impl) == "pallas":
+        return _rmsnorm_pallas(x, scale, eps, interpret)
     return ref.rmsnorm_ref(x, scale, eps=eps)

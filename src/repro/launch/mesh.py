@@ -13,10 +13,11 @@ Topology (TPU v5e target):
 """
 from __future__ import annotations
 
+import math
 from typing import Optional, Tuple
 
 import jax
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
 
 # hardware constants (TPU v5e) used by the roofline analysis
 PEAK_FLOPS_BF16 = 197e12       # per chip
@@ -26,19 +27,27 @@ DCN_BW = 6.25e9                # bytes/s per host pair (cross-pod, ~50 Gbit)
 CHIPS_PER_POD = 256
 
 
+def _auto_mesh(shape, axes, devices=None) -> Mesh:
+    # Auto axes: the model code places tensors with with_sharding_constraint,
+    # which rejects the Explicit axes jax.make_mesh defaults to
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
+
+
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_test_mesh(shape: Optional[Tuple[int, ...]] = None,
                    axes: Optional[Tuple[str, ...]] = None) -> Mesh:
-    """Mesh over whatever devices exist (CPU tests: usually one device)."""
-    n = jax.device_count()
+    """The Trainer/Server default mesh. Without ``shape`` it is (data=1,
+    model=1) on ``jax.devices()[0]``: one chip, however many the host holds —
+    more devices are used only when a caller asks for that shape."""
     if shape is None:
-        shape, axes = (1, n), ("data", "model")
-    return jax.make_mesh(shape, axes)
+        shape, axes = (1, 1), ("data", "model")
+    return _auto_mesh(shape, axes, jax.devices()[:math.prod(shape)])
 
 
 def mesh_axes(mesh: Mesh) -> Tuple[str, ...]:

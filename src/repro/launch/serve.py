@@ -19,6 +19,8 @@ def main() -> None:
     ap.add_argument("--max-len", type=int, default=128)
     ap.add_argument("--driver", action="store_true")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     prompts = [[1 + (i % 7), 2, 3 + i % 5] + [4] * (i % 4)
                for i in range(args.requests)]
@@ -38,6 +40,8 @@ def main() -> None:
                                   for p in prompts]})
         ok = plane.run_until_done([jid], max_ticks=500)
         print("job:", plane.job_status(jid), "ok:", ok)
+        if not ok:
+            raise SystemExit("serve job did not finish in the tick budget")
         return
 
     from repro.runtime.serve_loop import Server, ServeJobConfig

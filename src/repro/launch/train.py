@@ -34,6 +34,8 @@ def main() -> None:
     ap.add_argument("--clusters", type=int, default=2,
                     help="driver mode: number of private clusters")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     payload = {"arch": args.arch, "steps": args.steps, "seq_len": args.seq_len,
                "global_batch": args.global_batch, "mode": args.mode,

@@ -6,17 +6,48 @@ activations carry logical-axis sharding constraints through the ``MeshPlan``.
 """
 from __future__ import annotations
 
+import functools
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from repro.kernels import ops
 from repro.parallel.sharding import MeshPlan, constrain
 
 
-def rmsnorm(x: jax.Array, scale: jax.Array, eps: float) -> jax.Array:
-    return ops.rmsnorm(x, scale, eps=eps)
+# GSPMD cannot partition a Pallas kernel: on a mesh of several devices each
+# kernel below runs per shard under shard_map, on specs that keep whole every
+# dim the kernel reduces over.
+def _pallas_per_shard(plan: MeshPlan) -> bool:
+    return ops.resolve_impl() == "pallas" and plan.mesh.size > 1
+
+
+def rmsnorm(x: jax.Array, scale: jax.Array, eps: float, plan: MeshPlan,
+            logical=("batch",)) -> jax.Array:
+    """RMSNorm over the last dim of x, whose leading dims are ``logical``
+    (the rest are kept whole per shard)."""
+    if not _pallas_per_shard(plan):
+        return ops.rmsnorm(x, scale, eps=eps)
+    spec = plan.spec(logical, x.shape[:len(logical)])
+    return jax.shard_map(functools.partial(ops.rmsnorm, eps=eps),
+                         mesh=plan.mesh, in_specs=(spec, P()),
+                         out_specs=spec, check_vma=False)(x, scale)
+
+
+def flash_attention(plan: MeshPlan, q, k, v, causal: bool, window: int = 0):
+    """Flash attention over [B,S,H,D] q and [B,S,K,D] k/v. Per shard, batch
+    is split over the batch axes and heads over the axis that splits the kv
+    heads (q heads follow it, so each q head keeps its kv head)."""
+    if not _pallas_per_shard(plan):
+        return ops.flash_attention(q, k, v, causal=causal, window=window)
+    batch_ax, _, head_ax = (tuple(plan.spec(("batch", "seq", "kv_heads"),
+                                            k.shape[:3])) + (None,) * 3)[:3]
+    spec = P(batch_ax, None, head_ax, None)
+    fn = functools.partial(ops.flash_attention, causal=causal, window=window)
+    return jax.shard_map(fn, mesh=plan.mesh, in_specs=(spec, spec, spec),
+                         out_specs=spec, check_vma=False)(q, k, v)
 
 
 # ------------------------------------------------------------------------------ RoPE
@@ -50,10 +81,11 @@ def swiglu(p: dict, x: jax.Array, plan: MeshPlan) -> jax.Array:
 
 
 # -------------------------------------------------------------------------- attention
-def _qk_norm(p: dict, q: jax.Array, k: jax.Array, eps: float):
+def _qk_norm(p: dict, q: jax.Array, k: jax.Array, eps: float,
+             plan: MeshPlan):
     if "q_norm" in p:
-        q = ops.rmsnorm(q, p["q_norm"], eps=eps)
-        k = ops.rmsnorm(k, p["k_norm"], eps=eps)
+        q = rmsnorm(q, p["q_norm"], eps, plan, ("batch", "seq", "heads"))
+        k = rmsnorm(k, p["k_norm"], eps, plan, ("batch", "seq", "kv_heads"))
     return q, k
 
 
@@ -66,7 +98,7 @@ def qkv_project(p: dict, x: jax.Array, plan: MeshPlan, *,
     q = jnp.einsum("bsd,dhk->bshk", x, p["wq"])
     k = jnp.einsum("bsd,dhk->bshk", src, p["wk"])
     v = jnp.einsum("bsd,dhk->bshk", src, p["wv"])
-    q, k = _qk_norm(p, q, k, eps)
+    q, k = _qk_norm(p, q, k, eps, plan)
     if positions is not None:
         q = apply_rope(q, positions, theta)
         kp = kv_positions if kv_positions is not None else positions

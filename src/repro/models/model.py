@@ -88,7 +88,7 @@ def _self_attn(cfg: ArchConfig, plan: MeshPlan, p: dict, h: jax.Array,
                positions: jax.Array, window: int, causal: bool = True):
     q, k, v = LY.qkv_project(p, h, plan, positions=positions,
                              theta=cfg.rope_theta, eps=cfg.norm_eps)
-    o = ops.flash_attention(q, k, v, causal=causal, window=window)
+    o = LY.flash_attention(plan, q, k, v, causal, window)
     o = constrain(o, plan, ("batch", "seq", "heads", None))
     return LY.attn_out(p, o, plan), k, v
 
@@ -97,7 +97,7 @@ def _cross_attn(cfg: ArchConfig, plan: MeshPlan, p: dict, h: jax.Array,
                 memory: jax.Array):
     q, k, v = LY.qkv_project(p, h, plan, positions=None, theta=0.0,
                              eps=cfg.norm_eps, kv_from=memory)
-    o = ops.flash_attention(q, k, v, causal=False)
+    o = LY.flash_attention(plan, q, k, v, causal=False)
     o = constrain(o, plan, ("batch", "seq", "heads", None))
     return LY.attn_out(p, o, plan), k, v
 
@@ -125,17 +125,17 @@ def _block(cfg: ArchConfig, plan: MeshPlan, p: dict, x: jax.Array,
            positions: jax.Array, window: int, want_kv: bool,
            memory: Optional[jax.Array] = None, causal: bool = True):
     """attn [-> xattn] -> ff. Returns (x, kv, xkv, aux)."""
-    h = LY.rmsnorm(x, p["ln1"], cfg.norm_eps)
+    h = LY.rmsnorm(x, p["ln1"], cfg.norm_eps, plan)
     a, k, v = _self_attn(cfg, plan, p["attn"], h, positions, window, causal)
     x = x + a
     kv = {"k": k, "v": v} if want_kv else None
     xkv = None
     if "xattn" in p:
-        h = LY.rmsnorm(x, p["ln3"], cfg.norm_eps)
+        h = LY.rmsnorm(x, p["ln3"], cfg.norm_eps, plan)
         a, xk, xv = _cross_attn(cfg, plan, p["xattn"], h, memory)
         x = x + a
         xkv = {"k": xk, "v": xv} if want_kv else None
-    h = LY.rmsnorm(x, p["ln2"], cfg.norm_eps)
+    h = LY.rmsnorm(x, p["ln2"], cfg.norm_eps, plan)
     y, aux = _ff(cfg, plan, p, h, decode=False)
     return x + y, kv, xkv, aux
 
@@ -144,7 +144,7 @@ def _block_decode(cfg: ArchConfig, plan: MeshPlan, p: dict, x: jax.Array,
                   cache: dict, pos: jax.Array, window: int,
                   xkv: Optional[dict] = None):
     """Decode variant of ``_block``; cache is {"k","v"} (ring when window > 0)."""
-    h = LY.rmsnorm(x, p["ln1"], cfg.norm_eps)
+    h = LY.rmsnorm(x, p["ln1"], cfg.norm_eps, plan)
     positions = pos[:, None]
     q, k_new, v_new = LY.qkv_project(p["attn"], h, plan, positions=positions,
                                      theta=cfg.rope_theta, eps=cfg.norm_eps)
@@ -166,9 +166,9 @@ def _block_decode(cfg: ArchConfig, plan: MeshPlan, p: dict, x: jax.Array,
     o = constrain(o, plan, ("batch", "seq", "heads", None))
     x = x + LY.attn_out(p["attn"], o, plan)
     if "xattn" in p:
-        h = LY.rmsnorm(x, p["ln3"], cfg.norm_eps)
+        h = LY.rmsnorm(x, p["ln3"], cfg.norm_eps, plan)
         x = x + _cross_attn_cached(cfg, plan, p["xattn"], h, xkv["k"], xkv["v"])
-    h = LY.rmsnorm(x, p["ln2"], cfg.norm_eps)
+    h = LY.rmsnorm(x, p["ln2"], cfg.norm_eps, plan)
     y, _ = _ff(cfg, plan, p, h, decode=True)
     return x + y, {"k": k_c, "v": v_c}
 
@@ -240,7 +240,7 @@ def _ssm_fwd(cfg: ArchConfig, plan: MeshPlan, params: dict, x: jax.Array,
              want_state: bool = False):
     def body(x, inp):
         lp = inp
-        h = LY.rmsnorm(x, lp["ln1"], cfg.norm_eps)
+        h = LY.rmsnorm(x, lp["ln1"], cfg.norm_eps, plan)
         if want_state:
             y, st = SSM.ssm_block(cfg, lp["ssm"], h, plan, return_state=True)
             return x + y, st
@@ -255,7 +255,7 @@ def _ssm_decode(cfg: ArchConfig, plan: MeshPlan, params: dict, x: jax.Array,
                 states: dict):
     def body(x, inp):
         lp, st = inp
-        h = LY.rmsnorm(x, lp["ln1"], cfg.norm_eps)
+        h = LY.rmsnorm(x, lp["ln1"], cfg.norm_eps, plan)
         y, new = SSM.ssm_block(cfg, lp["ssm"], h, plan, state=st,
                                return_state=True)
         return x + y, new
@@ -275,10 +275,10 @@ def _hybrid_split(cfg: ArchConfig, params: dict):
 
 
 def _shared_block_fwd(cfg, plan, shared, x, positions, want_kv):
-    h = LY.rmsnorm(x, shared["ln1"], cfg.norm_eps)
+    h = LY.rmsnorm(x, shared["ln1"], cfg.norm_eps, plan)
     a, k, v = _self_attn(cfg, plan, shared["attn"], h, positions, 0)
     x = x + a
-    h = LY.rmsnorm(x, shared["ln2"], cfg.norm_eps)
+    h = LY.rmsnorm(x, shared["ln2"], cfg.norm_eps, plan)
     x = x + LY.swiglu(shared["mlp"], h, plan)
     return x, ({"k": k, "v": v} if want_kv else None)
 
@@ -293,7 +293,7 @@ def _hybrid_fwd(cfg: ArchConfig, plan: MeshPlan, params: dict, x: jax.Array,
         states, kvs = [], None
         for j in range(k):
             pj = tmap(lambda a: a[j], lp)
-            h = LY.rmsnorm(x, pj["ln1"], cfg.norm_eps)
+            h = LY.rmsnorm(x, pj["ln1"], cfg.norm_eps, plan)
             if want_state:
                 y, st = SSM.ssm_block(cfg, pj["ssm"], h, plan, return_state=True)
                 states.append(st)
@@ -309,7 +309,7 @@ def _hybrid_fwd(cfg: ArchConfig, plan: MeshPlan, params: dict, x: jax.Array,
     main_states, shared_kv = ys if want_state else (None, None)
 
     def tail_body(x, lp):
-        h = LY.rmsnorm(x, lp["ln1"], cfg.norm_eps)
+        h = LY.rmsnorm(x, lp["ln1"], cfg.norm_eps, plan)
         if want_state:
             y, st = SSM.ssm_block(cfg, lp["ssm"], h, plan, return_state=True)
             return x + y, st
@@ -332,7 +332,7 @@ def _hybrid_decode(cfg: ArchConfig, plan: MeshPlan, params: dict, x: jax.Array,
         for j in range(k):
             pj = tmap(lambda a: a[j], lp)
             st = tmap(lambda a: a[j], sts)
-            h = LY.rmsnorm(x, pj["ln1"], cfg.norm_eps)
+            h = LY.rmsnorm(x, pj["ln1"], cfg.norm_eps, plan)
             y, new = SSM.ssm_block(cfg, pj["ssm"], h, plan, state=st,
                                    return_state=True)
             new_states.append(new)
@@ -345,7 +345,7 @@ def _hybrid_decode(cfg: ArchConfig, plan: MeshPlan, params: dict, x: jax.Array,
 
     def tail_body(x, inp):
         lp, st = inp
-        h = LY.rmsnorm(x, lp["ln1"], cfg.norm_eps)
+        h = LY.rmsnorm(x, lp["ln1"], cfg.norm_eps, plan)
         y, new = SSM.ssm_block(cfg, lp["ssm"], h, plan, state=st,
                                return_state=True)
         return x + y, new
@@ -355,7 +355,7 @@ def _hybrid_decode(cfg: ArchConfig, plan: MeshPlan, params: dict, x: jax.Array,
 
 
 def _shared_decode(cfg, plan, shared, x, skv, pos):
-    h = LY.rmsnorm(x, shared["ln1"], cfg.norm_eps)
+    h = LY.rmsnorm(x, shared["ln1"], cfg.norm_eps, plan)
     positions = pos[:, None]
     q, k_new, v_new = LY.qkv_project(shared["attn"], h, plan,
                                      positions=positions, theta=cfg.rope_theta,
@@ -367,17 +367,17 @@ def _shared_decode(cfg, plan, shared, x, skv, pos):
     o = ops.attend_cache(q, k_c, v_c, pos[:, None, None, None],
                          packed=cfg.packed_decode)
     x = x + LY.attn_out(shared["attn"], o, plan)
-    h = LY.rmsnorm(x, shared["ln2"], cfg.norm_eps)
+    h = LY.rmsnorm(x, shared["ln2"], cfg.norm_eps, plan)
     x = x + LY.swiglu(shared["mlp"], h, plan)
     return x, {"k": k_c, "v": v_c}
 
 
 # -------------------------------------------------------------------------- vlm stack
 def _vlm_cross_layer(cfg, plan, p, x, patches, want_kv):
-    h = LY.rmsnorm(x, p["ln1"], cfg.norm_eps)
+    h = LY.rmsnorm(x, p["ln1"], cfg.norm_eps, plan)
     a, k, v = _cross_attn(cfg, plan, p["xattn"], h, patches)
     x = x + jnp.tanh(p["gate"].astype(jnp.float32)).astype(x.dtype) * a
-    h = LY.rmsnorm(x, p["ln2"], cfg.norm_eps)
+    h = LY.rmsnorm(x, p["ln2"], cfg.norm_eps, plan)
     x = x + LY.swiglu(p["mlp"], h, plan)
     return x, ({"k": k, "v": v} if want_kv else None)
 
@@ -416,10 +416,10 @@ def _vlm_decode(cfg: ArchConfig, plan: MeshPlan, params: dict, x: jax.Array,
             cj = tmap(lambda a: a[j], caches)
             x, nc = _block_decode(cfg, plan, pj, x, cj, pos, 0)
             new.append(nc)
-        h = LY.rmsnorm(x, clp["ln1"], cfg.norm_eps)
+        h = LY.rmsnorm(x, clp["ln1"], cfg.norm_eps, plan)
         a = _cross_attn_cached(cfg, plan, clp["xattn"], h, xkv["k"], xkv["v"])
         x = x + jnp.tanh(clp["gate"].astype(jnp.float32)).astype(x.dtype) * a
-        h = LY.rmsnorm(x, clp["ln2"], cfg.norm_eps)
+        h = LY.rmsnorm(x, clp["ln2"], cfg.norm_eps, plan)
         x = x + LY.swiglu(clp["mlp"], h, plan)
         return x, tmap(lambda *t: jnp.stack(t), *new)
 
@@ -453,7 +453,7 @@ class Model:
         return constrain(x, self.plan, ("batch", "seq", None))
 
     def _unembed(self, params: dict, x: jax.Array) -> jax.Array:
-        x = LY.rmsnorm(x, params["final_norm"], self.cfg.norm_eps)
+        x = LY.rmsnorm(x, params["final_norm"], self.cfg.norm_eps, self.plan)
         table = (params["embed"].T if self.cfg.tie_embeddings
                  else params["unembed"])
         logits = jnp.einsum("bsd,dv->bsv", x, table)
@@ -474,7 +474,7 @@ class Model:
 
         body = _remat(body, cfg.remat)
         x, _ = jax.lax.scan(body, x, params["enc_layers"])
-        return LY.rmsnorm(x, params["enc_norm"], cfg.norm_eps)
+        return LY.rmsnorm(x, params["enc_norm"], cfg.norm_eps, plan)
 
     # ----------------------------------------------------------------------- forward
     def forward(self, params: dict, batch: Dict[str, jax.Array],
@@ -504,7 +504,7 @@ class Model:
         else:
             raise ValueError(cfg.family)
         if return_hidden:
-            return LY.rmsnorm(x, params["final_norm"], cfg.norm_eps), aux
+            return LY.rmsnorm(x, params["final_norm"], cfg.norm_eps, plan), aux
         return self._unembed(params, x), aux
 
     def loss_fn(self, params: dict, batch: Dict[str, jax.Array]):

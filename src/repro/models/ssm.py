@@ -14,6 +14,7 @@ import jax.numpy as jnp
 
 from repro.configs.base import ArchConfig
 from repro.kernels import ops
+from repro.models import layers as LY
 from repro.parallel.sharding import MeshPlan, constrain
 
 
@@ -71,7 +72,7 @@ def ssm_block(cfg: ArchConfig, p: dict, x: jax.Array, plan: MeshPlan, *,
     y = y.reshape(B, S, DI)
 
     y = y * jax.nn.silu(z.astype(jnp.float32)).astype(y.dtype)   # gated
-    y = ops.rmsnorm(y, p["gate_norm"], eps=cfg.norm_eps)
+    y = LY.rmsnorm(y, p["gate_norm"], cfg.norm_eps, plan)
     y = constrain(y, plan, ("batch", "seq", "ffn"))
     out = jnp.einsum("be,ed->bd", y.reshape(B * S, DI),
                      p["out_proj"]).reshape(B, S, D)

@@ -15,6 +15,7 @@ family-specific code.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import itertools
 from collections import deque
 from typing import Deque, Dict, List, Optional
@@ -24,6 +25,7 @@ import jax.numpy as jnp
 
 from repro.configs import base as configs
 from repro.launch.mesh import make_test_mesh
+from repro.launch.steps import named
 from repro.models.model import Model
 from repro.parallel.sharding import MeshPlan
 
@@ -68,11 +70,18 @@ class Server:
         self.arch_cfg = arch_cfg
         mesh = mesh or make_test_mesh()
         self.model = Model(arch_cfg, MeshPlan(mesh=mesh, fsdp=False))
+        # params and cache are built straight into their shardings on the mesh
+        self._init_params_fn = jax.jit(
+            self.model.init_params,
+            out_shardings=named(mesh, self.model.param_specs()))
         self.params = params if params is not None else \
-            self.model.init_params(jax.random.PRNGKey(cfg.seed))
+            self._init_params_fn(jax.random.PRNGKey(cfg.seed))
 
         B, L = cfg.slots, cfg.max_len
-        self.cache = self.model.init_cache(B, L)
+        self._init_cache_fn = jax.jit(
+            functools.partial(self.model.init_cache, B, L),
+            out_shardings=named(mesh, self.model.cache_specs(B, L)))
+        self.cache = self._init_cache_fn()
         self._batch_axis = self._locate_batch_axes(L)
         self.slots: List[Optional[Request]] = [None] * B
         self.queue: Deque[Request] = deque()
@@ -93,11 +102,11 @@ class Server:
         if cfg.seed == self._init_seed:
             self.params = self._init_params
         else:
-            self.params = self.model.init_params(jax.random.PRNGKey(cfg.seed))
+            self.params = self._init_params_fn(jax.random.PRNGKey(cfg.seed))
             self._init_params = self.params
             self._init_seed = cfg.seed
         self.cfg = cfg
-        self.cache = self.model.init_cache(cfg.slots, cfg.max_len)
+        self.cache = self._init_cache_fn()
         self.slots = [None] * cfg.slots
         self.queue = deque()
         self.requests: Dict[str, Request] = {}

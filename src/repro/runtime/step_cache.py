@@ -27,7 +27,12 @@ worker's cached handlers and the module-level cold fallbacks:
   * eval — STRICT restore through ``CheckpointManager.restore``'s staleness/
     leaf checks: a missing or half-written checkpoint fails the task (and
     rides the retry machinery) instead of silently scoring fresh params.
-  * serve — synthetic prompts through the continuous-batching server.
+  * serve — the payload's ``requests`` (or synthetic prompts) through the
+    continuous-batching server.
+
+A train or eval task releases its trainer's device state when it ends, so
+a warm worker holds compiled steps, not a finished task's state, while its
+next task (say, a serve stage) needs the device memory.
 """
 from __future__ import annotations
 
@@ -124,7 +129,9 @@ def run_train_task(cache: Optional[TrainerCache], payload: dict) -> dict:
         resumed = tr.restore()
     ran = max(cfg.steps - tr.step, 0)
     m = tr.run(ran) if ran else {}
+    losses = tr.metrics.series("loss")
     out = {"steps": tr.step, "loss": m.get("loss", tr.loss()),
+           "first_loss": losses[0] if losses else None,
            "ran_steps": ran, "resumed_from": resumed,
            # StepTimer's EMA step wall time: the flight recorder folds it
            # into the task's execute span so a trace shows not just how long
@@ -132,6 +139,7 @@ def run_train_task(cache: Optional[TrainerCache], payload: dict) -> dict:
            "step_ema_s": tr.timer.ema_s}
     if cfg.checkpoint_dir:
         out["checkpoint"] = tr.save_checkpoint()
+    tr.release()        # a warm trainer keeps its compiled step, not its state
     return out
 
 
@@ -150,6 +158,7 @@ def run_eval_task(cache: Optional[TrainerCache], payload: dict) -> dict:
                                if cfg.mode == "local_sgd"
                                else tr.state["params"], batch)
     out["eval_loss"] = float(loss)
+    tr.release()
     return out
 
 
@@ -157,13 +166,18 @@ def run_serve_task(cache: Optional[ServerCache], payload: dict) -> dict:
     from repro.runtime.serve_loop import ServeJobConfig
     cfg = ServeJobConfig.from_job({"payload": dict(payload)})
     srv = (ServerCache(0) if cache is None else cache).get(cfg)
-    n = int(payload.get("n_requests", cfg.slots))
     max_new = int(payload.get("max_new", 8))
-    prompt_len = max(int(payload.get("prompt_len", 4)), 1)
-    vocab = srv.arch_cfg.vocab_size
-    for i in range(n):
-        srv.submit([(i + j) % vocab for j in range(prompt_len)],
-                   max_new=max_new)
+    if "requests" in payload:
+        # explicit prompts, as a plane serve job carries them
+        for r in payload["requests"]:
+            srv.submit(r["prompt"], max_new=int(r.get("max_new", max_new)))
+    else:
+        n = int(payload.get("n_requests", cfg.slots))
+        prompt_len = max(int(payload.get("prompt_len", 4)), 1)
+        vocab = srv.arch_cfg.vocab_size
+        for i in range(n):
+            srv.submit([(i + j) % vocab for j in range(prompt_len)],
+                       max_new=max_new)
     done = srv.run()
     return {"requests": len(done),
             "generated_tokens": sum(len(r.generated) for r in done),

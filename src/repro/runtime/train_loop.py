@@ -15,6 +15,7 @@ in tests/test_fault_tolerance.py).
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Callable, Dict, Optional
 
 import jax
@@ -24,7 +25,8 @@ from repro.checkpoint.manager import CheckpointManager
 from repro.configs import base as configs
 from repro.data.pipeline import SyntheticTokens
 from repro.launch.mesh import make_test_mesh
-from repro.launch.steps import init_train_state, make_train_step
+from repro.launch.steps import (init_train_state, make_train_step, named,
+                                train_state_specs)
 from repro.models.model import Model
 from repro.optim.adamw import AdamWConfig
 from repro.optim.local_sgd import (LocalSGDConfig, init_local_sgd_state,
@@ -82,23 +84,30 @@ class Trainer:
         if cfg.mode == "local_sgd":
             # pods are a leading vmapped dim; the model must not shard on "pod"
             self.model = Model(arch_cfg, pod_free_plan(self.plan))
-            params = self.model.init_params(jax.random.PRNGKey(cfg.seed))
-            self.state = init_local_sgd_state(params, cfg.n_pods)
+            n_pods = cfg.n_pods
+
+            def init(key):
+                return init_local_sgd_state(self.model.init_params(key), n_pods)
+
+            self._state_shardings = None
             spmd = "pod" if "pod" in mesh.shape else None
             self.round_fn = jax.jit(make_round_fn(
-                self.model.loss_fn, cfg.opt, cfg.local_sgd, spmd_axis=spmd))
+                self.model.loss_fn, cfg.opt, cfg.local_sgd, spmd_axis=spmd),
+                donate_argnums=(0,))
         else:
             self.model = Model(arch_cfg, self.plan)
-            self.state = init_train_state(self.model,
-                                          jax.random.PRNGKey(cfg.seed))
+            init = functools.partial(init_train_state, self.model)
+            self._state_shardings = named(
+                mesh, train_state_specs(arch_cfg, self.plan))
             self.step_fn = jax.jit(make_train_step(self.model, cfg.opt,
-                                                   cfg.microbatches))
+                                                   cfg.microbatches),
+                                   donate_argnums=(0,))
 
-        # pristine copies for ``rebind``: JAX updates are functional, so the
-        # initial tree can be handed back verbatim when a cached trainer is
-        # re-armed for a new task of the same compiled family
-        self._init_state = self.state
-        self._init_seed = cfg.seed
+        # every step donates (frees) the state it is given, so the initial
+        # state is never kept: ``rebind`` rebuilds it from the seed, which is
+        # deterministic, and it is built straight into its shardings
+        self._init_fn = jax.jit(init, out_shardings=self._state_shardings)
+        self.state = self._init_fn(jax.random.PRNGKey(cfg.seed))
         self.data = SyntheticTokens(
             vocab_size=arch_cfg.vocab_size, seq_len=cfg.seq_len,
             global_batch=cfg.global_batch, seed=cfg.seed, task=cfg.data_task)
@@ -120,17 +129,8 @@ class Trainer:
         mode, ...) matches; only per-run knobs may differ here."""
         if self.ckpt:
             self.ckpt.wait()             # bound the previous task's async save
-        if cfg.seed == self._init_seed:
-            self.state = self._init_state
-        else:
-            if cfg.mode == "local_sgd":
-                params = self.model.init_params(jax.random.PRNGKey(cfg.seed))
-                self.state = init_local_sgd_state(params, cfg.n_pods)
-            else:
-                self.state = init_train_state(self.model,
-                                              jax.random.PRNGKey(cfg.seed))
-            self._init_state = self.state
-            self._init_seed = cfg.seed
+        self.state = None                # free it before building the next
+        self.state = self._init_fn(jax.random.PRNGKey(cfg.seed))
         self.cfg = cfg
         self.step = 0
         self.data = SyntheticTokens(
@@ -241,10 +241,24 @@ class Trainer:
                 raise FileNotFoundError(
                     f"no committed checkpoint in {directory}")
             return 0
-        self.state, step, extra = mgr.restore(self.state, step=step)
+        like = jax.eval_shape(self._init_fn, jax.random.PRNGKey(0))
+        # free the device copy before loading the saved one: two full train
+        # states need not fit on the device (a failed load leaves no state;
+        # ``rebind`` builds one)
+        self.state = None
+        self.state, step, extra = mgr.restore(
+            like, step=step, shardings=self._state_shardings)
         self.data.load_state_dict(extra["data"])
         self.step = int(step)
         return self.step
+
+    def release(self) -> None:
+        """Free the device-resident train state between tasks: a finished
+        task's state lives on in its checkpoint, and ``rebind`` builds the
+        next task's state from its seed."""
+        if self.ckpt:
+            self.ckpt.wait()
+        self.state = None
 
     # -------------------------------------------------------------------- inspection
     def loss(self) -> Optional[float]:

@@ -16,7 +16,8 @@ SKIPS = [(a, s) for a in configs.names() for s in SHAPES
 
 @pytest.fixture(scope="module")
 def mesh():
-    return jax.make_mesh((1, 1, 1), ("pod", "data", "model"))
+    return jax.make_mesh((1, 1, 1), ("pod", "data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 3)
 
 
 @pytest.mark.parametrize("arch,shape", CELLS)

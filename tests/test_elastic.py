@@ -67,8 +67,9 @@ SUBPROCESS_REMESH = textwrap.dedent("""
     from repro.runtime.elastic import remesh_state
 
     cfg = dataclasses.replace(configs.get("qwen3-0.6b").reduced(), remat="none")
-    mesh8 = jax.make_mesh((4, 2), ("data", "model"))
-    mesh4 = jax.make_mesh((2, 2), ("data", "model"),
+    auto = (jax.sharding.AxisType.Auto,) * 2
+    mesh8 = jax.make_mesh((4, 2), ("data", "model"), axis_types=auto)
+    mesh4 = jax.make_mesh((2, 2), ("data", "model"), axis_types=auto,
                           devices=jax.devices()[:4])
     plan8, plan4 = MeshPlan(mesh=mesh8), MeshPlan(mesh=mesh4)
     model = Model(cfg, plan8)

@@ -71,6 +71,23 @@ def test_flash_custom_vjp_matches_autodiff_oracle():
                                    rtol=1e-3, atol=1e-3)
 
 
+@pytest.mark.parametrize("B,S,H,K,D,causal,window", FLASH_SWEEP)
+def test_flash_pallas_vjp_matches_blocked(B, S, H, K, D, causal, window):
+    """The Pallas branch's custom VJP (kernel forward, its lse into the
+    blocked backward) against ``_flash_blocked``'s gradients."""
+    q, k, v = _qkv(B, S, H, K, D, jnp.float32)
+    do = jax.random.normal(jax.random.PRNGKey(7), q.shape, jnp.float32)
+
+    def grads(impl, **kw):
+        _, vjp = jax.vjp(lambda *a: ops.flash_attention(
+            *a, causal=causal, window=window, impl=impl, **kw), q, k, v)
+        return vjp(do)
+
+    for a, b in zip(grads("pallas", interpret=True), grads("blocked")):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=2e-5, atol=2e-5)
+
+
 SSD_SWEEP = [
     # B, S, H, P, N, chunk
     (1, 128, 2, 32, 16, 32),
