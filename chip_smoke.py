@@ -36,8 +36,12 @@ import sys
 import tempfile
 import time
 from pathlib import Path
+from typing import TYPE_CHECKING
 
 import numpy as np
+
+if TYPE_CHECKING:
+    from repro.runtime.telemetry import CompileCounter
 
 ROOT = Path(__file__).resolve().parent
 ARCH = "qwen3-0.6b"
@@ -54,38 +58,6 @@ FLASH_TOL = 3e-2
 LOGIT_TOL = 0.08          # rtol = atol, as test_prefill_decode_matches_forward
 LOSS_TOL = 2e-2           # nats, |loss on 4 chips - loss on one|
 GRAD_TOL = 1e-3           # f32 gradients, 4 chips vs one, relative to max |g|
-BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
-CACHE_HIT = "/jax/compilation_cache/cache_hits"
-
-
-class CompileLog:
-    """Backend compile seconds and persistent-cache hits, from JAX's events.
-    A compile served from the cache counts with its (short) read time."""
-
-    def __init__(self):
-        import jax
-        self.seconds = 0.0
-        self.compiles = 0
-        self.hits = 0
-        jax.monitoring.register_event_duration_secs_listener(self._duration)
-        jax.monitoring.register_event_listener(self._event)
-
-    def _duration(self, event, secs, **_):
-        if event == BACKEND_COMPILE:
-            self.seconds += secs
-            self.compiles += 1
-
-    def _event(self, event, **_):
-        if event == CACHE_HIT:
-            self.hits += 1
-
-    def mark(self):
-        return self.seconds, self.compiles, self.hits
-
-    def since(self, mark) -> dict:
-        s, c, h = mark
-        return {"compile_s": self.seconds - s, "compiles": self.compiles - c,
-                "cache_hits": self.hits - h}
 
 
 class Checks:
@@ -114,7 +86,7 @@ def prompts(vocab: int):
 
 
 # ----------------------------------------------------------------- one chip
-def run_dag(ckpt_dir: str, log: CompileLog, checks: Checks) -> None:
+def run_dag(ckpt_dir: str, log: CompileCounter, checks: Checks) -> None:
     """train -> eval -> serve through the plane, as a user submits it."""
     from repro.core.plane import ManagementPlane
     from repro.pipelines import DAG, HybridComposer, Task
@@ -163,7 +135,7 @@ def run_dag(ckpt_dir: str, log: CompileLog, checks: Checks) -> None:
         ph = phases.get(name, {})
         if ph:
             print(f"phase {name}: wall {ph['wall_s']:.3f} s, compile "
-                  f"{ph['compile_s']:.3f} s ({ph['compiles']} programs, "
+                  f"{ph['compile_s']:.3f} s ({ph['loads']} programs, "
                   f"{ph['cache_hits']} from cache), run "
                   f"{ph['wall_s'] - ph['compile_s']:.3f} s", flush=True)
         checks.check(f"taskdb {name}", row.get("status") == "success",
@@ -185,7 +157,7 @@ def run_dag(ckpt_dir: str, log: CompileLog, checks: Checks) -> None:
                  f"{sv['generated_tokens']} decode_steps={sv['decode_steps']}")
 
 
-def check_programs(mesh, log: CompileLog, checks: Checks) -> None:
+def check_programs(mesh, log: CompileCounter, checks: Checks) -> None:
     """The train and prefill programs the tasks ran hold the Pallas kernel,
     and compiling the train program again is served by the cache."""
     import jax
@@ -334,7 +306,7 @@ def check_decode(mesh, checks: Checks) -> None:
         gc.collect()
 
 
-def one_chip(log: CompileLog, checks: Checks) -> None:
+def one_chip(log: CompileCounter, checks: Checks) -> None:
     import jax
     from repro.launch.mesh import make_test_mesh
 
@@ -409,7 +381,7 @@ def loss_and_grads(mesh):
                          for g in jax.tree_util.tree_leaves(grads)]
 
 
-def four_chips(log: CompileLog, checks: Checks) -> None:
+def four_chips(log: CompileCounter, checks: Checks) -> None:
     """The serve and train programs on (data=1, model=4) against one chip."""
     import jax
     from repro.launch.mesh import make_test_mesh
@@ -475,15 +447,16 @@ def main(argv=None) -> int:
         return 1
     sys.path.insert(0, str(ROOT / "src"))
     from repro.launch.compile_cache import enable_compile_cache
+    from repro.runtime.telemetry import COMPILES
 
     print(f"compile cache: {enable_compile_cache()}", flush=True)
     print(f"device: platform={dev.platform} device_kind={dev.device_kind} "
           f"count={len(jax.devices())} jax={jax.__version__}", flush=True)
     print(f"model: {ARCH} reduced={REDUCED} {arch_cfg()}", flush=True)
-    log, checks = CompileLog(), Checks()
+    log, checks = COMPILES, Checks()
     t0 = time.perf_counter()
     (four_chips if args.chips == 4 else one_chip)(log, checks)
-    print(f"total: {time.perf_counter() - t0:.3f} s, {log.compiles} "
+    print(f"total: {time.perf_counter() - t0:.3f} s, {log.loads} "
           f"programs compiled in {log.seconds:.3f} s, "
           f"{log.hits} from the cache", flush=True)
     if checks.failed:

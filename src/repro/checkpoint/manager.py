@@ -23,6 +23,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.runtime.telemetry import LoopSpans
+
 _SEP = "/"
 
 
@@ -50,6 +52,7 @@ class CheckpointManager:
         self.use_async = use_async
         self._thread: Optional[threading.Thread] = None
         self._commit_hooks: List[Callable[[int, str], None]] = []
+        self.spans = LoopSpans("ckpt")
         os.makedirs(directory, exist_ok=True)
 
     # ------------------------------------------------------------------------- hooks
@@ -104,11 +107,16 @@ class CheckpointManager:
             for hook in self._commit_hooks:
                 hook(step, os.path.join(target, "manifest.json"))
 
+        def traced_write():
+            # ``repro.ckpt.write``, on the thread that writes
+            with self.spans.span("write"):
+                write()
+
         if self.use_async and not blocking:
-            self._thread = threading.Thread(target=write, daemon=True)
+            self._thread = threading.Thread(target=traced_write, daemon=True)
             self._thread.start()
         else:
-            write()
+            traced_write()
         return target
 
     def wait(self) -> None:

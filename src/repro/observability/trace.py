@@ -96,7 +96,7 @@ class Span:
     """One timed segment of a trace, materialized from the event log on
     read. ``start``/``end`` are simulated fabric clock (deterministic, what
     the benchmarks gate); host-time facts arrive as attrs (``wall_s``,
-    ``step_ema_s``)."""
+    ``step_ema_s``, ``phase_ms``)."""
 
     __slots__ = ("span_id", "trace_id", "parent_id", "name", "component",
                  "start", "end", "status", "attrs")
@@ -476,7 +476,7 @@ def critical_path(tracer: Tracer, trace_id: str) -> Optional[dict]:
     placement, queue-wait, execution, and commit segments), ``dominant`` is
     the largest, and ``path`` is the greedy longest-child walk from the
     root. Durations are simulated-clock; host-time facts (``wall_s``,
-    ``step_ema_s``) live in each span's attrs.
+    ``step_ema_s``, ``phase_ms``) live in each span's attrs.
     """
     spans = tracer.trace(trace_id)
     if not spans:

@@ -83,6 +83,9 @@ from typing import Callable, Dict, List, Optional, Tuple
 
 from repro.pipelines.services import ServiceClient
 
+# host-time facts a task's result may carry, folded into its execute span
+HOST_ATTRS = ("step_ema_s", "phase_ms")
+
 
 def _etl(payload: dict) -> dict:
     import jax.numpy as jnp
@@ -336,12 +339,13 @@ class PipelineWorker:
         if ctx is not None:
             terminal = pair[-1]
             res = terminal.get("result")
-            ema = (res.get("step_ema_s")
-                   if isinstance(res, dict) else None)    # StepTimer's EMA
+            # StepTimer's EMA and per-phase host ms, when the task has them
+            host = ({k: res[k] for k in HOST_ATTRS if res.get(k) is not None}
+                    if isinstance(res, dict) else {})
             st = "ok" if terminal["status"] == "success" else "failed"
         else:
-            ema, st = None, "ok"
-        self._pending_trace.append((queue, wall, ctx, tnow, st, ema))
+            host, st = {}, "ok"
+        self._pending_trace.append((queue, wall, ctx, tnow, st, host))
         return pair
 
     def _finish_commit_trace(self) -> None:
@@ -355,17 +359,16 @@ class PipelineWorker:
         metrics = self.metrics
         if tr is None:
             if metrics is not None:
-                for queue, wall, _ctx, _t0, _st, _ema in pt:
+                for queue, wall, _ctx, _t0, _st, _host in pt:
                     metrics.observe(f"pipeline.service_time.{queue}", wall)
             return
         t1 = tr.clock()                  # one read per batch
         rec = tr.rec
-        for queue, wall, ctx, t0, st, ema in pt:
+        for queue, wall, ctx, t0, st, host in pt:
             if metrics is not None:
                 metrics.observe(f"pipeline.service_time.{queue}", wall)
             if ctx is not None:
-                a = ({"wall_s": wall} if ema is None
-                     else {"wall_s": wall, "step_ema_s": ema})
+                a = {"wall_s": wall, **host}
                 rec((None, ctx, "execute", "worker", t0, t0, st, a))
                 rec((None, ctx, "commit", "worker", t0, t1, "ok", None))
         tr.bound()

@@ -11,12 +11,23 @@ The batch axis of every cache leaf is located *generically* by diffing
 ``cache_defs(batch=1)`` against ``cache_defs(batch=2)`` — the same Server drives
 dense KV caches, MoE, ring-buffer windows, SSM states and hybrid caches without
 family-specific code.
+
+Each ``step`` is a ``repro.serve.step`` span holding ``admit`` (per request:
+``prefill`` with the prompt's ``length``, ``splice``, and ``sync`` for the
+first token), ``decode`` (the dispatch) and ``sync`` (sampling and the
+per-slot tokens brought to the host) (``repro.runtime.telemetry``).
+``spans.host_transfers`` counts the arrays brought to the host;
+``spans.compiles`` the programs loaded by span and step, so a new prompt
+length shows as a compile under ``prefill``. Each
+``Request`` carries ``time.perf_counter`` stamps: submitted, admitted (its
+prefill's start) and first token (its first sync's end).
 """
 from __future__ import annotations
 
 import dataclasses
 import functools
 import itertools
+import time
 from collections import deque
 from typing import Deque, Dict, List, Optional
 
@@ -28,6 +39,7 @@ from repro.launch.mesh import make_test_mesh
 from repro.launch.steps import named
 from repro.models.model import Model
 from repro.parallel.sharding import MeshPlan
+from repro.runtime.telemetry import LoopSpans
 
 tmap = jax.tree_util.tree_map
 
@@ -39,6 +51,9 @@ class Request:
     max_new: int = 16
     generated: List[int] = dataclasses.field(default_factory=list)
     done: bool = False
+    t_submit: Optional[float] = None
+    t_admit: Optional[float] = None
+    t_first: Optional[float] = None
 
 
 @dataclasses.dataclass
@@ -90,6 +105,7 @@ class Server:
         self._prefill_cache: Dict[int, object] = {}
         self._rng = jax.random.PRNGKey(cfg.seed + 17)
         self.steps = 0
+        self.spans = LoopSpans("serve")
         self._init_params = self.params
         self._init_seed = cfg.seed
 
@@ -113,6 +129,7 @@ class Server:
         self._ids = itertools.count(1)
         self._rng = jax.random.PRNGKey(cfg.seed + 17)
         self.steps = 0
+        self.spans = LoopSpans("serve")
 
     # ------------------------------------------------------------- batch-axis magic
     def _locate_batch_axes(self, L: int):
@@ -137,7 +154,8 @@ class Server:
     # ----------------------------------------------------------------- request path
     def submit(self, prompt: List[int], max_new: int = 16) -> str:
         rid = f"req-{next(self._ids):04d}"
-        req = Request(rid, list(prompt), max_new)
+        req = Request(rid, list(prompt), max_new,
+                      t_submit=time.perf_counter())
         self.queue.append(req)
         if not hasattr(self, "requests"):
             self.requests: Dict[str, Request] = {}
@@ -168,16 +186,22 @@ class Server:
         return jax.random.categorical(key, logits).astype(jnp.int32)
 
     def _admit(self) -> None:
+        spans = self.spans
         for slot in range(self.cfg.slots):
             if self.slots[slot] is not None or not self.queue:
                 continue
             req = self.queue.popleft()
-            toks = jnp.asarray([req.prompt], jnp.int32)
-            batch = {"tokens": toks, **self._aux_inputs(1)}
-            logits, one_cache = self._prefill_fn(len(req.prompt))(
-                self.params, batch)
-            self._splice(slot, one_cache)
-            first = int(self._sample(logits)[0])
+            with spans.span("prefill", length=len(req.prompt)) as prefill:
+                toks = jnp.asarray([req.prompt], jnp.int32)
+                batch = {"tokens": toks, **self._aux_inputs(1)}
+                logits, one_cache = self._prefill_fn(len(req.prompt))(
+                    self.params, batch)
+            with spans.span("splice"):
+                self._splice(slot, one_cache)
+            with spans.span("sync") as sync:
+                first = int(self._sample(logits)[0])
+            req.t_admit, req.t_first = prefill.t0, sync.t1
+            spans.host_transfers += 1
             req.generated.append(first)
             self.slots[slot] = req
             self._maybe_finish(slot)
@@ -197,18 +221,25 @@ class Server:
     # -------------------------------------------------------------------- main loop
     def step(self) -> int:
         """Admit + one batched decode step. Returns number of active slots."""
-        self._admit()
-        active = [i for i, r in enumerate(self.slots) if r is not None]
-        if not active:
-            return 0
-        last = [r.generated[-1] if r else 0 for r in self.slots]
-        tokens = jnp.asarray(last, jnp.int32)[:, None]
-        logits, self.cache = self._decode(self.params, tokens, self.cache)
-        nxt = self._sample(logits)
-        for i in active:
-            self.slots[i].generated.append(int(nxt[i]))
-            self._maybe_finish(i)
-        self.steps += 1
+        spans = self.spans
+        with spans.step(self.steps):
+            with spans.span("admit"):
+                self._admit()
+            active = [i for i, r in enumerate(self.slots) if r is not None]
+            if not active:
+                return 0
+            with spans.span("decode"):
+                last = [r.generated[-1] if r else 0 for r in self.slots]
+                tokens = jnp.asarray(last, jnp.int32)[:, None]
+                logits, self.cache = self._decode(self.params, tokens,
+                                                  self.cache)
+            with spans.span("sync"):
+                nxt = self._sample(logits)
+                for i in active:
+                    self.slots[i].generated.append(int(nxt[i]))
+                    self._maybe_finish(i)
+            spans.host_transfers += len(active)
+            self.steps += 1
         return len(active)
 
     def run(self, max_steps: int = 10_000) -> List[Request]:

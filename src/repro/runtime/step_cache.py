@@ -133,10 +133,12 @@ def run_train_task(cache: Optional[TrainerCache], payload: dict) -> dict:
     out = {"steps": tr.step, "loss": m.get("loss", tr.loss()),
            "first_loss": losses[0] if losses else None,
            "ran_steps": ran, "resumed_from": resumed,
-           # StepTimer's EMA step wall time: the flight recorder folds it
-           # into the task's execute span so a trace shows not just how long
-           # a train task took but how fast its steps were going
-           "step_ema_s": tr.timer.ema_s}
+           # StepTimer's EMA step wall time and mean host ms per phase of
+           # the step (batch, dispatch, sync, log, checkpoint): the flight
+           # recorder folds them into the task's execute span so a trace
+           # shows not just how long a train task took but how fast its
+           # steps were going, and where their host time went
+           "step_ema_s": tr.timer.ema_s, "phase_ms": tr.timer.phase_ms}
     if cfg.checkpoint_dir:
         out["checkpoint"] = tr.save_checkpoint()
     tr.release()        # a warm trainer keeps its compiled step, not its state

@@ -11,6 +11,14 @@ Two synchronization modes, selected per job:
 Deterministic restart: checkpoint = (train state, data step, RNG seed); the data
 pipeline is a pure function of step, so kill/restore resumes bit-exact (validated
 in tests/test_fault_tolerance.py).
+
+Each ``step_once`` is a ``repro.train.step`` span holding, in order,
+``batch`` (the feed), ``dispatch`` (the step program, which only enqueues),
+``sync`` (the metrics brought to the host: the loop's one wait on the
+device), ``log`` (the metrics row) and, when one is due, ``checkpoint``
+(``repro.runtime.telemetry``). ``spans.host_transfers`` counts the arrays
+brought to the host; ``spans.compiles`` the programs loaded by span and
+step.
 """
 from __future__ import annotations
 
@@ -32,7 +40,7 @@ from repro.optim.adamw import AdamWConfig
 from repro.optim.local_sgd import (LocalSGDConfig, init_local_sgd_state,
                                    make_round_fn, pod_free_plan)
 from repro.parallel.sharding import MeshPlan
-from repro.runtime.telemetry import MetricsLog, StepTimer
+from repro.runtime.telemetry import LoopSpans, MetricsLog, StepTimer
 
 tmap = jax.tree_util.tree_map
 
@@ -113,6 +121,7 @@ class Trainer:
             global_batch=cfg.global_batch, seed=cfg.seed, task=cfg.data_task)
         self.metrics = MetricsLog()
         self.timer = StepTimer(tokens_per_step=cfg.global_batch * cfg.seq_len)
+        self.spans = LoopSpans("train", self.timer)
         self.ckpt = (CheckpointManager(cfg.checkpoint_dir)
                      if cfg.checkpoint_dir else None)
         if self.ckpt and on_checkpoint:
@@ -138,6 +147,7 @@ class Trainer:
             global_batch=cfg.global_batch, seed=cfg.seed, task=cfg.data_task)
         self.metrics = MetricsLog()
         self.timer = StepTimer(tokens_per_step=cfg.global_batch * cfg.seq_len)
+        self.spans = LoopSpans("train", self.timer)
         self.ckpt = (CheckpointManager(cfg.checkpoint_dir)
                      if cfg.checkpoint_dir else None)
         if self.ckpt and on_checkpoint:
@@ -173,23 +183,33 @@ class Trainer:
         return tmap(lambda *x: jnp.stack(x), *rows)
 
     def step_once(self) -> Dict[str, float]:
-        if self.cfg.mode == "local_sgd":
-            batches = self._round_batches(self.step)
-            self.state, m = self.round_fn(self.state, batches)
-            self.step += self.cfg.local_sgd.inner_steps
-        else:
-            batch = self._sync_batch(self.step)
-            self.state, m = self.step_fn(self.state, batch)
-            self.step += 1
-        m = {k: float(v) for k, v in m.items()}
-        self.timer.tick()
-        self.metrics.log(self.step, m)
-        if (self.ckpt and self.step % self.cfg.checkpoint_every == 0):
-            # non-blocking: the manager snapshots host leaves synchronously,
-            # then writes on its thread while the next steps run — periodic
-            # checkpointing leaves the hot loop (save() itself serializes
-            # against a still-running previous write)
-            self.save_checkpoint(blocking=False)
+        spans = self.spans
+        with spans.step(self.step):
+            if self.cfg.mode == "local_sgd":
+                with spans.span("batch"):
+                    batches = self._round_batches(self.step)
+                with spans.span("dispatch"):
+                    self.state, m = self.round_fn(self.state, batches)
+                n = self.cfg.local_sgd.inner_steps
+            else:
+                with spans.span("batch"):
+                    batch = self._sync_batch(self.step)
+                with spans.span("dispatch"):
+                    self.state, m = self.step_fn(self.state, batch)
+                n = 1
+            self.step += n
+            with spans.span("sync"):
+                m = {k: float(v) for k, v in m.items()}
+            spans.host_transfers += len(m)
+            with spans.span("log"):
+                self.metrics.log(self.step, m)
+            if (self.ckpt and self.step % self.cfg.checkpoint_every == 0):
+                # non-blocking: the manager snapshots host leaves
+                # synchronously, then writes on its thread while the next
+                # steps run — periodic checkpointing leaves the hot loop
+                # (save() itself serializes against a still-running
+                # previous write)
+                self.save_checkpoint(blocking=False)
         return m
 
     def run(self, steps: Optional[int] = None) -> Dict[str, float]:
@@ -207,11 +227,13 @@ class Trainer:
         explicit blocking save) joins it."""
         if not self.ckpt:
             return None
-        self.ckpt.save(self.step, self.state,
-                       extra={"data": self.data.state_dict(),
-                              "arch": self.cfg.arch, "mode": self.cfg.mode})
-        if blocking:
-            self.ckpt.wait()
+        with self.spans.span("checkpoint"):
+            self.ckpt.save(self.step, self.state,
+                           extra={"data": self.data.state_dict(),
+                                  "arch": self.cfg.arch,
+                                  "mode": self.cfg.mode})
+            if blocking:
+                self.ckpt.wait()
         return {"step": self.step, "path": str(self.ckpt.directory)}
 
     def restore(self, manifest: Optional[dict] = None,
@@ -224,33 +246,34 @@ class Trainer:
         see a committed checkpoint, never silently score fresh params. All
         integrity checks (manifest-vs-directory staleness, missing leaves,
         torn writes) are ``CheckpointManager.restore``'s and always raise."""
-        if self.ckpt:
-            self.ckpt.wait()             # our own async save is a valid source
-        directory = (manifest or {}).get("path") or (
-            self.cfg.checkpoint_dir if self.ckpt else None)
-        if directory is None:
-            if strict:
-                raise FileNotFoundError(
-                    f"restore requested but no checkpoint directory in "
-                    f"manifest or config: {manifest!r}")
-            return 0
-        mgr = CheckpointManager(directory)
-        step = (manifest or {}).get("step") or mgr.latest_step()
-        if step is None:
-            if strict:
-                raise FileNotFoundError(
-                    f"no committed checkpoint in {directory}")
-            return 0
-        like = jax.eval_shape(self._init_fn, jax.random.PRNGKey(0))
-        # free the device copy before loading the saved one: two full train
-        # states need not fit on the device (a failed load leaves no state;
-        # ``rebind`` builds one)
-        self.state = None
-        self.state, step, extra = mgr.restore(
-            like, step=step, shardings=self._state_shardings)
-        self.data.load_state_dict(extra["data"])
-        self.step = int(step)
-        return self.step
+        with self.spans.span("checkpoint"):
+            if self.ckpt:
+                self.ckpt.wait()         # our own async save is a valid source
+            directory = (manifest or {}).get("path") or (
+                self.cfg.checkpoint_dir if self.ckpt else None)
+            if directory is None:
+                if strict:
+                    raise FileNotFoundError(
+                        f"restore requested but no checkpoint directory in "
+                        f"manifest or config: {manifest!r}")
+                return 0
+            mgr = CheckpointManager(directory)
+            step = (manifest or {}).get("step") or mgr.latest_step()
+            if step is None:
+                if strict:
+                    raise FileNotFoundError(
+                        f"no committed checkpoint in {directory}")
+                return 0
+            like = jax.eval_shape(self._init_fn, jax.random.PRNGKey(0))
+            # free the device copy before loading the saved one: two full
+            # train states need not fit on the device (a failed load leaves
+            # no state; ``rebind`` builds one)
+            self.state = None
+            self.state, step, extra = mgr.restore(
+                like, step=step, shardings=self._state_shardings)
+            self.data.load_state_dict(extra["data"])
+            self.step = int(step)
+            return self.step
 
     def release(self) -> None:
         """Free the device-resident train state between tasks: a finished

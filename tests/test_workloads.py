@@ -258,6 +258,9 @@ def test_train_task_resume_exactly_once_accounting(tmp_path):
     r1 = run_train_task(None, payload)
     assert r1["steps"] == 4 and r1["ran_steps"] == 4
     assert r1["resumed_from"] == 0 and r1["checkpoint"]["step"] == 4
+    # mean host ms per phase of the step, for the worker's execute span
+    assert set(r1["phase_ms"]) == {"step", "batch", "dispatch", "sync", "log",
+                                   "checkpoint"}
     # redelivery after the checkpoint committed: nothing re-runs
     r2 = run_train_task(None, dict(payload))
     assert r2["steps"] == 4 and r2["ran_steps"] == 0
