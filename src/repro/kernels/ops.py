@@ -3,9 +3,10 @@
 impl resolution (``impl=None`` picks by backend, see ``resolve_impl``):
   * "pallas"  — pl.pallas_call TPU kernel: the default on a TPU, and only there
                 (tests run it on the CPU with interpret=True)
-  * "blocked" — pure-jnp block-streaming implementation with identical math and
-                O(S)-memory: the default on every other backend (CPU, incl. the
-                multi-pod dry-run)
+  * "blocked" — pure-jnp block-streaming implementation of the same algorithm,
+                in f32 throughout (the flash kernel multiplies bf16 inputs on
+                the MXU), O(S)-memory: the default on every other backend (CPU,
+                incl. the multi-pod dry-run)
   * "naive"   — ref.py oracle (small shapes / tests only)
 
 Both ``flash_attention`` paths carry a custom VJP implementing the block-wise flash

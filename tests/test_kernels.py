@@ -7,16 +7,18 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.kernels import ops, ref
+from repro.kernels import flash_attention as fa, ops, ref
 
 KEY = jax.random.PRNGKey(0)
 
 
 def _qkv(B, S, H, K, D, dtype):
+    """``S`` is one length, or (Sq, Skv)."""
+    Sq, Skv = S if isinstance(S, tuple) else (S, S)
     ks = jax.random.split(KEY, 3)
-    q = jax.random.normal(ks[0], (B, S, H, D), jnp.float32).astype(dtype)
-    k = jax.random.normal(ks[1], (B, S, K, D), jnp.float32).astype(dtype)
-    v = jax.random.normal(ks[2], (B, S, K, D), jnp.float32).astype(dtype)
+    q = jax.random.normal(ks[0], (B, Sq, H, D), jnp.float32).astype(dtype)
+    k = jax.random.normal(ks[1], (B, Skv, K, D), jnp.float32).astype(dtype)
+    v = jax.random.normal(ks[2], (B, Skv, K, D), jnp.float32).astype(dtype)
     return q, k, v
 
 
@@ -28,6 +30,12 @@ FLASH_SWEEP = [
     (1, 128, 4, 4, 64, False, 0),       # bidirectional (encoder)
     (1, 256, 4, 2, 64, True, 64),       # sliding window
     (1, 96, 2, 2, 80, True, 0),         # ragged: S % block, D % 128 != 0
+    # blocks chosen from the shape, wider than 128
+    (1, 1024, 4, 2, 128, True, 0),      # GQA at the train cell's length
+    (1, 1000, 4, 2, 128, True, 0),      # ragged above 512: 896 + 104
+    (1, 1024, 4, 2, 128, True, 256),    # sliding window
+    (1, 2048, 2, 1, 128, True, 256),    # window across blocks: a dead block
+    (1, (384, 1024), 4, 2, 128, False, 0),  # Sq < Skv, bidirectional
 ]
 
 
@@ -86,6 +94,100 @@ def test_flash_pallas_vjp_matches_blocked(B, S, H, K, D, causal, window):
     for a, b in zip(grads("pallas", interpret=True), grads("blocked")):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=2e-5, atol=2e-5)
+
+
+# head-major (B, Sq, Skv, H, K, causal, window, blk_q, blk_kv): blocks given
+# explicitly, so that several q and kv blocks meet the masks
+FLASH_BLOCKS = [
+    (1, 512, 512, 4, 2, True, 0, 128, 128),
+    (1, 512, 512, 4, 2, True, 0, 128, 256),
+    (1, 512, 512, 4, 2, True, 0, 256, 128),
+    (1, 512, 512, 2, 2, True, 128, 128, 128),
+    (1, 384, 640, 2, 1, True, 0, 128, 256),     # Sq < Skv, causal
+    (1, 384, 384, 2, 1, True, 192, 128, 256),   # window, ragged kv blocks
+    (1, 256, 512, 2, 2, False, 0, 128, 128),
+]
+
+
+@pytest.mark.parametrize("B,Sq,Skv,H,K,causal,window,blk_q,blk_kv", FLASH_BLOCKS)
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_flash_pallas_blocks_vs_ref(B, Sq, Skv, H, K, causal, window, blk_q,
+                                    blk_kv, dtype):
+    q, k, v = _qkv(B, (Sq, Skv), H, K, 128, dtype)
+    hm = lambda t: t.transpose(0, 2, 1, 3)
+    o, lse = fa.flash_attention_pallas(hm(q), hm(k), hm(v), causal=causal,
+                                       window=window, blk_q=blk_q,
+                                       blk_kv=blk_kv, interpret=True)
+    want = ref.attention_ref(q, k, v, causal=causal, window=window)
+    tol = 2e-2 if dtype == jnp.bfloat16 else 2e-5
+    np.testing.assert_allclose(np.asarray(hm(o), np.float32),
+                               np.asarray(want, np.float32),
+                               rtol=tol, atol=tol)
+    assert lse.shape == (B, H, Sq) and lse.dtype == jnp.float32
+
+
+def test_flash_block_chooser():
+    """Blocks follow the shape: whole dims up to 128, multiples of 128 above,
+    at most ``BLOCK``, and inside the VMEM budget."""
+    assert fa.pick_blocks(1024, 1024, 128, 2) == (1024, 1024)
+    assert fa.pick_blocks(5, 5, 128, 2) == (5, 5)
+    assert fa.pick_blocks(96, 200, 128, 4) == (96, 128)
+    assert fa.pick_blocks(1000, 1000, 128, 2) == (896, 896)
+    assert fa.pick_blocks(384, 1024, 128, 2) == (384, 1024)
+    # f32 inputs, and a wider head, halve the kv block to fit
+    assert fa.pick_blocks(1024, 1024, 128, 4) == (1024, 512)
+    assert fa.pick_blocks(8192, 8192, 256, 2) == (1024, 512)
+    for sq, skv, d, isz in [(1024, 1024, 128, 2), (8192, 8192, 256, 4),
+                            (300, 4096, 512, 4), (130, 130, 128, 2)]:
+        bq, bkv = fa.pick_blocks(sq, skv, d, isz)
+        for n, b in ((sq, bq), (skv, bkv)):
+            assert b <= fa.BLOCK and (b == n <= fa.LANES or b % fa.LANES == 0)
+        assert fa._vmem_bytes(bq, bkv, d, isz) <= fa.VMEM_BUDGET
+
+
+@pytest.mark.parametrize("Sq,Skv,causal,window,blk_q,blk_kv", [
+    (1024, 1024, True, 0, 128, 128),
+    (1024, 1024, True, 0, 256, 128),
+    (1024, 1024, True, 256, 128, 128),
+    (1000, 1000, True, 300, 128, 256),
+    (512, 2048, True, 0, 128, 512),
+    (2048, 2048, False, 0, 512, 512),
+])
+def test_flash_dead_steps_keep_kv_block(Sq, Skv, causal, window, blk_q, blk_kv):
+    """The k/v index map against the mask itself: a step whose blocks share
+    no unmasked pair reads the block of the step before it (or, first in its
+    row, the first block it will use), so it costs no copy; every other step
+    reads its own block."""
+    offset = Skv - Sq
+    nq, nkv = -(-Sq // blk_q), -(-Skv // blk_kv)
+    q_pos = np.arange(Sq)[:, None] + offset
+    k_pos = np.arange(Skv)[None, :]
+    mask = np.ones((Sq, Skv), bool)
+    if causal:
+        mask &= k_pos <= q_pos
+    if window:
+        mask &= q_pos - k_pos < window
+    dead_steps = 0
+    for i in range(nq):
+        idx = [int(fa.kv_block_index(i, j, blk_q=blk_q, blk_kv=blk_kv,
+                                     offset=offset, causal=causal,
+                                     window=window, num_kv_blocks=nkv))
+               for j in range(nkv)]
+        live = [mask[i * blk_q:(i + 1) * blk_q,
+                     j * blk_kv:(j + 1) * blk_kv].any() for j in range(nkv)]
+        for j in range(nkv):
+            if live[j]:
+                assert idx[j] == j
+            elif j > 0:
+                dead_steps += 1
+                assert idx[j] == idx[j - 1], (i, j, idx)
+            else:
+                dead_steps += 1
+                assert idx[0] == live.index(True), (i, idx)
+    if causal and not window and Sq == Skv and blk_q == blk_kv:
+        assert dead_steps == nq * (nq - 1) // 2
+    if not causal and not window:
+        assert dead_steps == 0
 
 
 SSD_SWEEP = [

@@ -8,14 +8,20 @@ may load the TPU library, and under several pytest workers only the worker
 that runs this file does.
 """
 import os
+import re
+import sys
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.configs import base as configs
-from repro.kernels import ops
+sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
+
+from bench.trace_reduce import FLASH_FORWARD_OP  # noqa: E402
+from repro.configs import base as configs  # noqa: E402
+from repro.kernels import ops  # noqa: E402
 
 
 @pytest.fixture(scope="module")
@@ -40,10 +46,10 @@ def _compile_text(fn, *shapes, sharding):
     return jax.jit(fn).lower(*args).compile().as_text()
 
 
-def _flash_shapes(S):
+def _flash_shapes(S, B=1):
     cfg = configs.get("qwen3-0.6b")
     H, K, D = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
-    return [((1, S, H, D), jnp.bfloat16)] + [((1, S, K, D), jnp.bfloat16)] * 2
+    return [((B, S, H, D), jnp.bfloat16)] + [((B, S, K, D), jnp.bfloat16)] * 2
 
 
 def _flash(q, k, v):
@@ -54,6 +60,13 @@ def _flash(q, k, v):
 def test_flash_forward_compiles(one_chip, S):
     text = _compile_text(_flash, *_flash_shapes(S), sharding=one_chip)
     assert "tpu_custom_call" in text
+
+
+def test_flash_forward_is_the_benchmarks_kernel(one_chip):
+    """At the train cell's shape (B 2, S 1024) the forward is one custom call,
+    in the form the benchmark's trace reader times as the flash kernel."""
+    text = _compile_text(_flash, *_flash_shapes(1024, B=2), sharding=one_chip)
+    assert len(re.findall(FLASH_FORWARD_OP, text)) == 1
 
 
 @pytest.mark.parametrize("S", [512, 5])
